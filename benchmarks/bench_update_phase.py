@@ -105,11 +105,8 @@ def bench_at_size(n_units: int, m: int, capacity: int = 768,
 
     impls = {
         "ref": functools.partial(run_impl, update_phase_reference),
-        "pallas": functools.partial(
-            run_impl, functools.partial(update_phase_op, interpret=True)),
-        "sparse": functools.partial(
-            run_impl,
-            functools.partial(update_phase_sparse, interpret=True)),
+        "pallas": functools.partial(run_impl, update_phase_op),
+        "sparse": functools.partial(run_impl, update_phase_sparse),
         "auto": functools.partial(run_impl, auto),
     }
     if capacity <= DENSE_CAPACITY_LIMIT:

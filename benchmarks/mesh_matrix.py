@@ -10,15 +10,20 @@ This benchmark measures aggregate ``signals/sec`` for a B=8 fleet at
 ndev=1 unsharded baseline, and lands in ``BENCH_gson.json:
 mesh_matrix``.
 
-Each cell runs in a fresh subprocess — XLA device-count flags must be
-set before jax first initializes, exactly like
+On the CPU each cell runs in a fresh subprocess — XLA device-count
+flags must be set before jax first initializes, exactly like
 ``tests/conftest.run_with_devices``. Host "devices" are threads over
 the same physical cores, so absolute scaling is bounded by the
-machine's core count (this container: measured numbers in
-EXPERIMENTS.md §Sharding); the table's job is to pin the *shape* of
-the curve and catch structural regressions (a sharded program that
-suddenly inserts collectives or resharding copies shows up as a
-falling ``speedup_vs_1dev`` long before a TPU pod ever runs it).
+machine's core count (measured numbers in EXPERIMENTS.md §Sharding);
+the table's job is to pin the *shape* of the curve and catch
+structural regressions (a sharded program that suddenly inserts
+collectives or resharding copies shows up as a falling
+``speedup_vs_1dev`` long before a TPU pod ever runs it).
+
+On an accelerator the cells run in this process over the first
+``ndev`` of ``jax.devices()`` (counts above the visible devices are
+skipped): a chip belongs to the one process that opened it, so a child
+could not reach it.
 """
 from __future__ import annotations
 
@@ -38,22 +43,21 @@ NDEVS = (1, 2, 4, 8)
 BATCH = 8
 
 
-def _worker(args) -> None:
-    """One cell, inside the forced-device-count subprocess."""
+def _measure(variant: str, ndev: int, batch: int, iters: int) -> dict:
+    """One cell: a B-network fleet on ``ndev`` devices, timed warm."""
     from repro import gson
     from repro.core.gson.state import GSONParams
 
     spec = gson.RunSpec(
-        variant=args.variant,
+        variant=variant,
         model=GSONParams(model="gwr", insertion_threshold=0.3),
         sampler="sphere", capacity=128, max_deg=12,
-        max_iterations=args.iters, check_every=20,
+        max_iterations=iters, check_every=20,
         qe_threshold=1e-9,              # never converges: fixed workload
         n_probe=256)
-    mesh = (gson.MeshSpec(axis="network", devices=args.ndev)
-            if args.ndev > 1 else None)
-    fspec = gson.FleetSpec.broadcast(spec, seeds=range(args.batch),
-                                     mesh=mesh)
+    mesh = (gson.MeshSpec(axis="network", devices=ndev)
+            if ndev > 1 else None)
+    fspec = gson.FleetSpec.broadcast(spec, seeds=range(batch), mesh=mesh)
 
     def once() -> int:
         fleet = gson.FleetSession(fspec)
@@ -63,12 +67,36 @@ def _worker(args) -> None:
     once()                              # warmup: compile
     t0 = time.perf_counter()
     signals = once()
-    wall = time.perf_counter() - t0
-    print(json.dumps({"signals": signals, "wall": wall}))
+    return {"signals": signals, "wall": time.perf_counter() - t0}
+
+
+def _worker(args) -> None:
+    """One cell, inside the forced-device-count subprocess."""
+    print(json.dumps(_measure(args.variant, args.ndev, args.batch,
+                              args.iters)))
 
 
 def _cell(variant: str, ndev: int, iters: int) -> dict:
+    import jax
+
+    if jax.default_backend() != "cpu":
+        payload = _measure(variant, ndev, BATCH, iters)
+    else:
+        payload = _host_cell(variant, ndev, iters)
+    return {
+        "variant": variant,
+        "batch": BATCH,
+        "ndev": ndev,
+        "iters_per_net": iters,
+        "wall": round(payload["wall"], 3),
+        "sps": round(payload["signals"] / payload["wall"], 1),
+    }
+
+
+def _host_cell(variant: str, ndev: int, iters: int) -> dict:
+    """One CPU cell in a child with ``ndev`` forced host devices."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={ndev}")
     env["PYTHONPATH"] = os.path.join(REPO_ROOT, "src") + (
@@ -83,27 +111,23 @@ def _cell(variant: str, ndev: int, iters: int) -> dict:
         raise RuntimeError(
             f"mesh_matrix worker (ndev={ndev}) failed:\n"
             f"{proc.stdout}\n{proc.stderr[-2000:]}")
-    payload = json.loads(proc.stdout.strip().splitlines()[-1])
-    return {
-        "variant": variant,
-        "batch": BATCH,
-        "ndev": ndev,
-        "iters_per_net": iters,
-        "wall": round(payload["wall"], 3),
-        "sps": round(payload["signals"] / payload["wall"], 1),
-    }
+    return json.loads(proc.stdout.strip().splitlines()[-1])
 
 
 def run(budget: str = "quick") -> list[dict]:
+    import jax
+
     from benchmarks.common import emit
 
     iters = {"quick": 40, "full": 120}[budget]
     variants = (("multi-fused",) if budget == "quick"
                 else ("multi", "multi-fused"))
+    ndevs = (NDEVS if jax.default_backend() == "cpu"
+             else tuple(n for n in NDEVS if n <= len(jax.devices())))
     rows = []
     for variant in variants:
         base_sps = None
-        for ndev in NDEVS:
+        for ndev in ndevs:
             row = _cell(variant, ndev, iters)
             if ndev == 1:
                 base_sps = row["sps"]

@@ -35,6 +35,9 @@ def main(argv=None):
 
     import jax
 
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     t0 = time.time()
     results = {}
     if want("per_signal"):

@@ -14,7 +14,7 @@ magnitude in CPU wall time (the paper's single-signal bunny consumed
      the sequential variants and hardware-independent.
 
 Implementations: single (sequential reference), indexed (hash grid),
-multi (batched jnp), kernel (Pallas find_winners, interpret=True).
+multi (batched jnp), kernel (Pallas find_winners; interpreted on a CPU).
 """
 from __future__ import annotations
 

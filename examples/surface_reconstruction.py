@@ -49,6 +49,7 @@ import numpy as np
 
 from repro import gson
 from repro.core.gson import metrics
+from repro.utils.compile_cache import enable_compile_cache
 
 GENUS = {"sphere": 0, "torus": 1, "eight": 2, "trefoil": 1}
 THRESH = {"sphere": 0.35, "torus": 0.25, "eight": 0.22, "trefoil": 0.12}
@@ -206,6 +207,7 @@ def main(argv=None):
     ap.add_argument("--resume", action="store_true",
                     help="continue from the newest snapshot")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.fleet:
         run_fleet(args)

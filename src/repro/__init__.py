@@ -7,7 +7,3 @@ Reproduction (and beyond-paper optimization) of:
 """
 
 __version__ = "0.1.0"
-
-# Forward-compat aliases (jax.shard_map / jax.set_mesh on 0.4.x) must be
-# in place before any repro submodule references them.
-from repro.utils import jax_compat as _jax_compat  # noqa: E402,F401

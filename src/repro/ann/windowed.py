@@ -77,7 +77,9 @@ class WindowedFindWinners:
 
         x2 = jnp.sum(signals * signals, axis=1, keepdims=True)    # (m, 1)
         w2 = jnp.sum(w * w, axis=1)                               # (C,)
-        d2 = x2 - 2.0 * signals @ w.T + w2[None, :]               # (m, C)
+        xw = jnp.matmul(signals, w.T,
+                        precision=jax.lax.Precision.HIGHEST)
+        d2 = x2 - 2.0 * xw + w2[None, :]                          # (m, C)
         d2 = jnp.where(active[None, :], d2, jnp.inf)
 
         pad = rows * L - C

@@ -182,6 +182,34 @@ def signal_sharded_find_winners(mesh: Mesh, signal_axes=("data",),
     return data_parallel_find_winners(mesh, signal_axes, inner=inner)
 
 
+@lru_cache(maxsize=None)
+def replicated_update_phase(mesh: Mesh, update_phase):
+    """``update_phase`` as an explicitly replicated shard_map program.
+
+    Under data partitioning the Update phase is a replicated
+    deterministic state machine: every device applies the identical
+    update to its full copy of the network. GSPMD replicates plain XLA
+    ops by itself, but a Pallas (Mosaic) kernel cannot be partitioned
+    automatically and must sit inside a shard_map — this wrapper is
+    that shard_map, with every operand and result replicated.
+
+    Memoized per ``(mesh, update_phase)``: the returned callable is a
+    jit cache key of every program that threads it.
+    """
+    rep = partial(jax.shard_map, mesh=mesh, in_specs=P(), out_specs=P(),
+                  check_vma=False)
+
+    def up(state, signals, wid, sid, d2b, k_lock, params,
+           signal_mask=None):
+        # params is static configuration: closed over, not an operand
+        def body(state, signals, wid, sid, d2b, k_lock, signal_mask):
+            return update_phase(state, signals, wid, sid, d2b, k_lock,
+                                params, signal_mask)
+        return rep(body)(state, signals, wid, sid, d2b, k_lock, signal_mask)
+
+    return up
+
+
 # ---------------------------------------------------------------------------
 # Fleet sharding: B whole networks sharded across devices, zero
 # per-iteration collectives (the paper's data-partitioning argument one

@@ -19,7 +19,10 @@ def quantization_error(state: NetworkState, probes: jax.Array) -> jax.Array:
     """Mean squared distance from probe signals to their winner."""
     x2 = jnp.sum(probes * probes, axis=1, keepdims=True)
     w2 = jnp.sum(state.w * state.w, axis=1)
-    d2 = x2 - 2.0 * probes @ state.w.T + w2[None, :]
+    # HIGHEST: QE drives convergence, and a single bf16 pass on a TPU
+    # would swamp a converged network's d^2 in rounding error
+    xw = jnp.matmul(probes, state.w.T, precision=jax.lax.Precision.HIGHEST)
+    d2 = x2 - 2.0 * xw + w2[None, :]
     d2 = jnp.where(state.active[None, :], d2, jnp.inf)
     return jnp.mean(jnp.maximum(jnp.min(d2, axis=1), 0.0))
 

@@ -84,7 +84,9 @@ def find_winners_reference(signals: jax.Array, w: jax.Array,
                            active: jax.Array):
     """Pure-jnp batched top-2 nearest units.
 
-    dist^2 = |x|^2 - 2 x.w + |w|^2 on the MXU-friendly matmul form.
+    dist^2 = |x|^2 - 2 x.w + |w|^2 on the MXU-friendly matmul form, at
+    HIGHEST precision: a TPU's default single bf16 pass rounds x.w to
+    ~3 digits, coarser than a converged winner's dist^2.
     Top-2 via two masked-min passes (O(mC); ``lax.top_k`` sorts the
     whole row, which dominated step time in profiling — same
     first-lowest-id tie semantics). Returns
@@ -92,7 +94,8 @@ def find_winners_reference(signals: jax.Array, w: jax.Array,
     """
     x2 = jnp.sum(signals * signals, axis=1, keepdims=True)        # (m, 1)
     w2 = jnp.sum(w * w, axis=1)                                   # (C,)
-    d2 = x2 - 2.0 * signals @ w.T + w2[None, :]                   # (m, C)
+    xw = jnp.matmul(signals, w.T, precision=jax.lax.Precision.HIGHEST)
+    d2 = x2 - 2.0 * xw + w2[None, :]                              # (m, C)
     d2 = jnp.where(active[None, :], d2, jnp.inf)
     wid = jnp.argmin(d2, axis=1).astype(jnp.int32)
     d2b = jnp.take_along_axis(d2, wid[:, None], axis=1)[:, 0]

@@ -228,6 +228,7 @@ def _is_connected(m: jax.Array, valid: jax.Array) -> jax.Array:
     reach = m | jnp.eye(K, dtype=bool)
     n_sq = max(1, K.bit_length())
     for _ in range(n_sq):
+        # 0/1 operands with small integer sums: exact at any precision
         reach = reach | (
             (reach.astype(jnp.float32) @ reach.astype(jnp.float32)) > 0)
     first = jnp.argmax(valid)

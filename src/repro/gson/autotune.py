@@ -26,11 +26,16 @@ Table resolution order (first hit wins):
 1. an explicit ``table=`` argument (``SelectionTable`` or a path);
 2. ``$REPRO_AUTOTUNE_TABLE`` — an operator override, loaded strictly
    (a broken override is an error, not a silent fallback);
-3. the local cache ``$REPRO_AUTOTUNE_CACHE`` (default
-   ``.runs/autotune_table.json``), written by :func:`autotune` —
-   loaded leniently (corrupt/stale-schema caches are warned away);
+3. a local cache, only when ``$REPRO_AUTOTUNE_CACHE`` names it (write
+   one with ``python -m repro.gson.autotune --out``) — loaded leniently
+   (corrupt/stale-schema caches are warned away);
 4. the committed default ``autotune_table.json`` next to this module,
    regenerated by ``python -m repro.gson.autotune``.
+
+Every table read from a file records the JAX backend it was measured
+on (``meta.backend``); loading one on another backend raises
+:class:`TableBackendError` — timings from one device say nothing about
+another, so the table must be regenerated on this one.
 
 ``tests/test_autotune.py`` pins: fake-timer determinism, JSON
 round-trip + schema-version rejection, nearest-cell fallback, the env
@@ -49,10 +54,11 @@ import warnings
 from dataclasses import dataclass, field
 from typing import Any, Callable, Mapping
 
+from repro.kernels import PlatformMismatchError
+
 SCHEMA_VERSION = 1
 ENV_TABLE = "REPRO_AUTOTUNE_TABLE"
 ENV_CACHE = "REPRO_AUTOTUNE_CACHE"
-DEFAULT_CACHE = os.path.join(".runs", "autotune_table.json")
 PACKAGED_TABLE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                               "autotune_table.json")
 
@@ -78,6 +84,10 @@ TimerFn = Callable[[str, Callable[[], Any]], float]
 
 class TableSchemaError(ValueError):
     """A selection table whose ``schema`` is not SCHEMA_VERSION."""
+
+
+class TableBackendError(PlatformMismatchError):
+    """A selection table measured on another JAX backend."""
 
 
 @dataclass(frozen=True)
@@ -277,8 +287,18 @@ def save_table(table: SelectionTable, path: str) -> str:
 
 
 def _read_table(path: str) -> SelectionTable:
+    import jax
+
     with open(path) as f:
-        return SelectionTable.from_json(json.load(f))
+        table = SelectionTable.from_json(json.load(f))
+    measured_on = table.meta.get("backend")
+    if measured_on != jax.default_backend():
+        raise TableBackendError(
+            f"autotune table {path!r} was measured on backend "
+            f"{measured_on!r}, this process runs on "
+            f"{jax.default_backend()!r}; regenerate it on this device "
+            "with `python -m repro.gson.autotune --out PATH`")
+    return table
 
 
 def load_table(source: str | SelectionTable | None = None,
@@ -287,8 +307,9 @@ def load_table(source: str | SelectionTable | None = None,
 
     ``source``: a ready table (returned as-is), an explicit path
     (loaded strictly), or ``None`` for the env → cache → committed
-    chain. Only the lenient cache step degrades silently (with a
-    RuntimeWarning); explicit paths and the env override raise.
+    chain. Only the lenient cache step degrades (with a
+    RuntimeWarning), and never past a backend mismatch; explicit paths
+    and the env override raise.
     """
     if isinstance(source, SelectionTable):
         return source
@@ -297,8 +318,8 @@ def load_table(source: str | SelectionTable | None = None,
     env = os.environ.get(ENV_TABLE)
     if env:
         return _read_table(env)
-    cache = os.environ.get(ENV_CACHE, DEFAULT_CACHE)
-    if os.path.exists(cache):
+    cache = os.environ.get(ENV_CACHE)
+    if cache and os.path.exists(cache):
         try:
             return _read_table(cache)
         except (ValueError, OSError, KeyError) as e:
@@ -307,16 +328,6 @@ def load_table(source: str | SelectionTable | None = None,
                 f"({type(e).__name__}: {e}); using the committed "
                 "default table", RuntimeWarning, stacklevel=2)
     return _read_table(PACKAGED_TABLE)
-
-
-def autotune(cells: tuple[tuple[int, int, int], ...] = DEFAULT_CELLS,
-             *, cache: str | None = None, n: int = 5, warmup: int = 2,
-             ) -> SelectionTable:
-    """Measure on THIS machine and persist to the local cache, which
-    :func:`load_table` prefers over the committed default."""
-    table = build_table(cells, n=n, warmup=warmup)
-    save_table(table, cache or os.environ.get(ENV_CACHE, DEFAULT_CACHE))
-    return table
 
 
 # ---------------------------------------------------------------------------

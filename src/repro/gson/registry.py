@@ -31,6 +31,7 @@ from typing import Any, Callable, Generic, Iterator, TypeVar
 from repro.core.gson.multi import find_winners_reference
 from repro.core.gson.sampling import SURFACES, make_sampler
 from repro.core.gson.state import GSONParams
+from repro.kernels import PlatformMismatchError
 
 T = TypeVar("T")
 
@@ -305,6 +306,8 @@ def resolve_backend(backend: str | Any | None) -> Backend:
         factory = BACKENDS.get(backend)
         try:
             return factory()
+        except PlatformMismatchError:
+            raise
         except Exception as e:                  # noqa: BLE001
             # a kernel backend whose construction fails (missing Pallas
             # toolchain, import error in the kernel package) must not
@@ -334,7 +337,12 @@ def reference_fallback(find_winners, update_phase,
     contract, so the run proceeds with the same results, just slower.
     Session and fleet drivers call this around their first step only —
     lowering failures surface on the first call of a compiled program.
+    A :class:`~repro.kernels.PlatformMismatchError` (kernels on a
+    non-TPU accelerator, an autotune table from another device) is not
+    a lowering failure: ``None``, so the caller re-raises it.
     """
+    if isinstance(err, PlatformMismatchError):
+        return None
     if ((find_winners is None or find_winners is find_winners_reference)
             and update_phase is None):
         return None

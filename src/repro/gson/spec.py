@@ -162,7 +162,7 @@ def resolve(spec: RunSpec) -> tuple[VariantStrategy, Runtime]:
             f"variant {strategy.name!r} takes a "
             f"{strategy.config_cls.__name__}, got {type(vcfg).__name__}")
     be = resolve_backend(spec.backend)
-    find_winners = be.find_winners
+    find_winners, update_phase = be.find_winners, be.update_phase
     if spec.mesh is not None:
         if spec.mesh.axis != "signal":
             raise ValueError(
@@ -172,16 +172,22 @@ def resolve(spec: RunSpec) -> tuple[VariantStrategy, Runtime]:
         # memoized per (mesh, axes, backend): ONE sharded adapter
         # instance, so every program that keys its jit cache on the
         # find_winners callable compiles once
-        from repro.core.gson.distributed import signal_sharded_find_winners
+        from repro.core.gson.distributed import (
+            replicated_update_phase, signal_sharded_find_winners)
         find_winners = signal_sharded_find_winners(
             spec.mesh.build(), (spec.mesh.axis_name,),
             inner=be.find_winners)
+        if update_phase is not None:
+            # a Pallas Update kernel outside a shard_map cannot be
+            # partitioned across the mesh's devices
+            update_phase = replicated_update_phase(spec.mesh.build(),
+                                                   update_phase)
     rt = Runtime(
         spec=spec,
         params=resolve_model(spec.model),
         vcfg=vcfg,
         sampler=resolve_sampler(spec.sampler),
         find_winners=find_winners,
-        update_phase=be.update_phase,
+        update_phase=update_phase,
     )
     return strategy, rt

@@ -30,10 +30,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 LARGE = 1e30  # plain float: jnp scalars would be captured consts in the kernel
 
-# jax < 0.5 names it TPUCompilerParams; newer releases CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 
 def _two_smallest_with_ids(d2: jax.Array, ids: jax.Array):
     """Row-wise two smallest values (+their ids) of (bm, n). Ties -> lowest id."""
@@ -57,11 +53,14 @@ def _find_winners_kernel(x_ref, w_ref, act_ref, out_d_ref, out_i_ref,
     w = w_ref[...]                       # (bc, d)  VMEM staged tile
     act = act_ref[...]                   # (1, bc)  1.0 active / 0.0 masked
 
-    # ||x||^2 - 2 x.w + ||w||^2 — the matmul hits the MXU.
+    # ||x||^2 - 2 x.w + ||w||^2 — the matmul hits the MXU. HIGHEST:
+    # one bf16 pass would round x.w to ~3 digits, far coarser than a
+    # converged winner's d^2, and scramble the top-2.
     x2 = jnp.sum(x * x, axis=1, keepdims=True)
     w2 = jnp.sum(w * w, axis=1)[None, :]
     xw = jax.lax.dot_general(
         x, w, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)                       # (bm, bc)
     # inactive/padded slots masked IN the kernel (bias add, no branch) —
     # the wrapper no longer materializes a bias row in HBM per call
@@ -114,7 +113,7 @@ def find_winners_pallas_padded(
             jax.ShapeDtypeStruct((m, 2), jnp.float32),
             jax.ShapeDtypeStruct((m, 2), jnp.int32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -8,6 +8,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.find_winners.kernel import LARGE, find_winners_pallas_padded
 
 
@@ -28,8 +29,7 @@ def find_winners_op(signals: jax.Array, w: jax.Array, active: jax.Array,
     inside the kernel via the (1, C) activity row, and signals/w are
     padded only when their static shape is actually misaligned.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = interpret_mode(interpret)
     m, d = signals.shape
     c = w.shape[0]
     block_m = min(block_m, _round_up(m, 8))

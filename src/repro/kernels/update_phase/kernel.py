@@ -48,16 +48,15 @@ from jax.experimental.pallas import tpu as pltpu
 # plain ints/floats: jnp scalars would be captured consts in the kernel
 BIG_PRIO = jnp.iinfo(jnp.int32).max
 
-# jax < 0.5 names it TPUCompilerParams; newer releases CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 
 def _col_dot(a: jax.Array, b: jax.Array) -> jax.Array:
     """(bm, bc) x (bm, n) -> (bc, n), contracting the signal axis on
-    the MXU with f32 accumulation."""
+    the MXU with f32 accumulation. HIGHEST precision: the one-hot
+    contractions must copy f32 payloads exactly, which one bf16 pass
+    would round to ~3 significant digits."""
     return jax.lax.dot_general(
         a, b, (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
 
 
@@ -111,7 +110,7 @@ def winner_lock_pallas_padded(
         ],
         out_specs=pl.BlockSpec((1, block_c), lambda i, j: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, capacity), jnp.int32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(wid, prio)
@@ -258,7 +257,7 @@ def update_accum_pallas_padded(
             jax.ShapeDtypeStruct((c, 1), jnp.float32),
             jax.ShapeDtypeStruct((c, 1), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(signals, wid, sel, adapt, scale_b, d2b, dec_b, nb, scale_n,
@@ -320,7 +319,7 @@ def edge_age_pallas_padded(
         ],
         out_specs=pl.BlockSpec((block_c, k), row),
         out_shape=jax.ShapeDtypeStruct((c, k), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(age, valid, win, winat, prot, protat, reset)

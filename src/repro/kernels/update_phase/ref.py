@@ -26,6 +26,12 @@ from repro.core.gson.state import GSONParams, NetworkState
 _BIG = jnp.iinfo(jnp.int32).max
 
 
+def _t_dot(a: jax.Array, b: jax.Array) -> jax.Array:
+    """``a.T @ b`` at HIGHEST precision: the one-hot contractions copy
+    f32 payloads, which a TPU's default bf16 pass would round."""
+    return jnp.matmul(a.T, b, precision=jax.lax.Precision.HIGHEST)
+
+
 def update_phase_dense(
     state: NetworkState,
     signals: jax.Array,
@@ -72,8 +78,8 @@ def update_phase_dense(
     # ---- winner pull: one-hot copy (post-lock winners are distinct) ------
     o_adapt = (onehot & adapt[:, None]).astype(jnp.float32)
     o_sel = (onehot & selected[:, None]).astype(jnp.float32)
-    scale_vec = o_adapt.T @ scale_b[:, None]                 # (C, 1)
-    sel_x = o_adapt.T @ signals                              # (C, d)
+    scale_vec = _t_dot(o_adapt, scale_b[:, None])            # (C, 1)
+    sel_x = _t_dot(o_adapt, signals)                         # (C, d)
     w1 = state.w + scale_vec * (sel_x - state.w)
 
     # ---- neighbor pulls: slot-summed weighted one-hot --------------------
@@ -89,20 +95,20 @@ def update_phase_dense(
             == jnp.arange(C, dtype=jnp.int32)[None, None, :])
     wn = jnp.sum(o_nb * scale_n[:, :, None], axis=1)         # (m, C)
     nsc = jnp.sum(wn, axis=0)[:, None]                       # (C, 1)
-    nsx = wn.T @ signals                                     # (C, d)
+    nsx = _t_dot(wn, signals)                                # (C, d)
     w2 = w1 + (nsx - nsc * w1)
 
     # ---- habituation + GNG error -----------------------------------------
     if is_gng:
         firing = state.firing
-        error = state.error + (o_sel.T @ d2b[:, None])[:, 0]
+        error = state.error + _t_dot(o_sel, d2b[:, None])[:, 0]
     else:
         dec_b = params.tau_b * (h_b - params.h_min)
         dec_n = jnp.where(nb_valid,
                           params.tau_n * (h_n - params.h_min), 0.0)
         dn = jnp.sum(o_nb * dec_n[:, :, None], axis=1)
         firing = jnp.clip(
-            state.firing - (o_adapt.T @ dec_b[:, None])[:, 0]
+            state.firing - _t_dot(o_adapt, dec_b[:, None])[:, 0]
             - jnp.sum(dn, axis=0),
             params.h_min, 1.0)
         error = state.error

@@ -44,6 +44,7 @@ from repro.core.gson import topology as topo
 from repro.core.gson.multi import (UpdateOut, stable_units,
                                    update_phase_inputs)
 from repro.core.gson.state import GSONParams, NetworkState
+from repro.kernels import interpret_mode
 from repro.kernels.update_phase.kernel import (BIG_PRIO,
                                                edge_age_pallas_padded,
                                                update_accum_pallas_padded,
@@ -94,8 +95,7 @@ def update_phase_sparse(
             "the sparse update-phase kernel implements the deterministic "
             '"sum" neighbor-collision mode only; use the reference '
             'backend to study neighbor_collision="last"')
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = interpret_mode(interpret)
     C, K = state.capacity, state.max_deg
     m, d = signals.shape
     is_gng = params.model == "gng"

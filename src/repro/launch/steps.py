@@ -277,9 +277,7 @@ def build_train_step(bundle: ModelBundle, mesh, rules, dep: DeployCfg):
 
     step = make_train_step(
         bundle, mesh, rules, tcfg,
-        act_ctx=lambda: activation_sharding(
-            act, mesh,
-            manual_axes=frozenset({"pod"}) if pod_manual else frozenset()))
+        act_ctx=lambda: activation_sharding(act, mesh))
 
     params, specs = param_tree(bundle, mesh, rules)
     opt_specs = opt_lib.match_opt_specs(

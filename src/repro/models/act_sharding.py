@@ -22,8 +22,6 @@ import jax
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.utils import jax_compat
-
 _STATE = threading.local()
 
 
@@ -52,16 +50,9 @@ class ActivationSharding:
 
 
 @contextlib.contextmanager
-def activation_sharding(spec: ActivationSharding, mesh,
-                        manual_axes: frozenset = frozenset()):
-    """``manual_axes``: mesh axes that are Manual in the enclosing
-    shard_map (e.g. {'pod'} in the compression/straggler train step).
-    Constraints inside such a region need manual-subgroup-marked
-    shardings, which only native ``jax.shard_map`` produces — on the
-    legacy shim, :func:`constrain` becomes a no-op there instead of
-    aborting XLA (see ``utils.jax_compat.has_native_shard_map``)."""
+def activation_sharding(spec: ActivationSharding, mesh):
     prev = getattr(_STATE, "ctx", None)
-    _STATE.ctx = (spec, mesh, frozenset(manual_axes))
+    _STATE.ctx = (spec, mesh)
     try:
         yield
     finally:
@@ -79,13 +70,7 @@ def constrain(x: jax.Array, kind: str = "residual") -> jax.Array:
     ctx = getattr(_STATE, "ctx", None)
     if ctx is None:
         return x
-    spec, mesh, manual_axes = ctx
-    if manual_axes and not jax_compat.has_native_shard_map():
-        # legacy shard_map cannot mark inner shardings as manual
-        # subgroups; emitting the constraint would abort XLA
-        # ("Check failed: sharding.IsManualSubgroup()") — drop the
-        # hint and let GSPMD propagate operand shardings instead
-        return x
+    spec, mesh = ctx
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
     if kind == "residual":
         ps = spec.residual_spec(x.shape, sizes)

@@ -17,6 +17,9 @@ def run_with_devices(code: str, n_devices: int = 8,
     Raises on failure with the subprocess output in the message.
     """
     env = dict(os.environ)
+    # host devices only: the child must never reach for an accelerator
+    # that this process may already hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={n_devices}")
     env["PYTHONPATH"] = os.path.join(

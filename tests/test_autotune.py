@@ -13,7 +13,9 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import warnings
 
+import jax
 import numpy as np
 import pytest
 
@@ -34,14 +36,16 @@ def tiny_cells():
     return ((8, 64, 16), (8, 256, 16))
 
 
-def hand_table(cells):
-    """A table built without any jax work (hand-written Cells)."""
+def hand_table(cells, backend=None):
+    """A table built without any jax work (hand-written Cells),
+    keyed to ``backend`` (default: this process's)."""
     made = tuple(
         at.Cell(units=u, capacity=c, m=m,
                 best=min(FAKE_US, key=lambda k: (FAKE_US[k], k)),
                 t_us=dict(FAKE_US))
         for (u, c, m) in cells)
-    return at.SelectionTable(cells=made)
+    return at.SelectionTable(
+        cells=made, meta={"backend": backend or jax.default_backend()})
 
 
 # ---------------------------------------------------------------------------
@@ -103,6 +107,36 @@ def test_env_override_wins(tmp_path, monkeypatch):
     monkeypatch.setenv(at.ENV_TABLE, str(tmp_path / "broken.json"))
     with pytest.raises(json.JSONDecodeError):
         at.load_table()
+
+
+def test_table_from_another_backend_raises(tmp_path, monkeypatch):
+    """A table measured on one backend never dispatches on another:
+    not as an explicit path, the env override, or the local cache."""
+    path = at.save_table(hand_table(((4, 32, 8),), backend="tpu-elsewhere"),
+                         str(tmp_path / "foreign.json"))
+    with pytest.raises(at.TableBackendError, match="regenerate"):
+        at.load_table(path)
+    monkeypatch.setenv(at.ENV_TABLE, path)
+    with pytest.raises(at.TableBackendError):
+        at.load_table()
+    monkeypatch.delenv(at.ENV_TABLE)
+    monkeypatch.setenv(at.ENV_CACHE, path)
+    with pytest.raises(at.TableBackendError):
+        at.load_table()
+
+
+def test_local_cache_read_only_when_named(tmp_path, monkeypatch):
+    """A table left in the working directory steers nothing: only a
+    cache that ``$REPRO_AUTOTUNE_CACHE`` names is read."""
+    cache = hand_table(((4, 32, 8),))
+    monkeypatch.delenv(at.ENV_TABLE, raising=False)
+    monkeypatch.delenv(at.ENV_CACHE, raising=False)
+    monkeypatch.chdir(tmp_path)
+    at.save_table(cache, str(tmp_path / ".runs" / "autotune_table.json"))
+    assert at.load_table() == at.load_table(at.PACKAGED_TABLE)
+    monkeypatch.setenv(at.ENV_CACHE,
+                       str(tmp_path / ".runs" / "autotune_table.json"))
+    assert at.load_table() == cache
 
 
 def test_corrupt_cache_warns_and_falls_through(tmp_path, monkeypatch):
@@ -217,6 +251,50 @@ def test_registry_pallas_auto_is_shared_and_runs():
                                rtol=1e-5, atol=1e-6)
 
 
+@pytest.mark.parametrize("driver,variant", [("session", "multi"),
+                                            ("fleet", "multi-fused")])
+def test_foreign_table_fails_the_run(tmp_path, monkeypatch, driver,
+                                     variant):
+    """A table from another device fails a pallas-auto run through the
+    entry points. It loads at the first trace, inside the first step,
+    where the lowering fallback must not turn it into a warning and a
+    reference run."""
+    path = at.save_table(hand_table(((4, 32, 8),), backend="tpu-elsewhere"),
+                         str(tmp_path / "foreign.json"))
+    monkeypatch.setenv(at.ENV_TABLE, path)
+    spec = gson.RunSpec(variant=variant, model="gwr", sampler="sphere",
+                        backend="pallas-auto", capacity=128, max_deg=12,
+                        max_iterations=8, check_every=8,
+                        qe_threshold=1e-4, n_probe=128)
+    with warnings.catch_warnings():
+        warnings.simplefilter("error", RuntimeWarning)
+        with pytest.raises(at.TableBackendError, match="regenerate"):
+            if driver == "session":
+                gson.run(spec, seed=0)
+            else:
+                gson.run_fleet(gson.FleetSpec.broadcast(spec, seeds=(0, 1)))
+
+
+def test_platform_mismatch_is_not_a_lowering_failure(monkeypatch):
+    """Kernels asked to run on a non-TPU accelerator raise
+    PlatformMismatchError, and the lowering fallback hands it back to
+    the caller instead of swapping in the reference."""
+    from repro.gson import registry
+    from repro.kernels import PlatformMismatchError, interpret_mode
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(PlatformMismatchError, match="'gpu'"):
+        interpret_mode(None)
+    assert interpret_mode(True) is True
+    be = gson.resolve_backend("pallas-full")
+    with warnings.catch_warnings():
+        warnings.simplefilter("error", RuntimeWarning)
+        for err in (PlatformMismatchError("gpu"),
+                    at.TableBackendError("foreign table")):
+            assert registry.reference_fallback(
+                be.find_winners, be.update_phase, err) is None
+
+
 # ---------------------------------------------------------------------------
 # the cliff can never silently return
 
@@ -240,8 +318,14 @@ def test_units_1024_cliff_regression():
         s, x, p, refresh_states=False, update_phase=up))
     step_ref = jax.jit(lambda s, x: multi_signal_step_impl(
         s, x, p, refresh_states=False))
-    _, t_auto = timed(step_auto, st, sig, n=3, warmup=2)
-    _, t_ref = timed(step_ref, st, sig, n=3, warmup=2)
+    for step in (step_auto, step_ref):
+        timed(step, st, sig, n=1, warmup=2)
+    # interleaved single calls, min of each: load from other processes
+    # (parallel test workers) then falls on both sides alike
+    t_auto, t_ref = float("inf"), float("inf")
+    for _ in range(5):
+        t_auto = min(t_auto, timed(step_auto, st, sig, n=1, warmup=0)[1])
+        t_ref = min(t_ref, timed(step_ref, st, sig, n=1, warmup=0)[1])
     assert t_auto <= 1.1 * t_ref, (
         f"pallas-auto {t_auto * 1e3:.1f}ms vs reference "
         f"{t_ref * 1e3:.1f}ms at the units=1024 cliff")

@@ -7,21 +7,9 @@ and smoke-cell lowering on a (pod, data, model) mesh.
 """
 from __future__ import annotations
 
-import jax
 import pytest
 
 pytestmark = pytest.mark.slow
-
-# repro.utils.jax_compat aliases jax.shard_map/jax.set_mesh onto legacy
-# jax.experimental.shard_map for the pinned 0.4.x container. Most
-# multi-device paths work through the alias; the partially-manual
-# (axis_names={'pod'}) train step does not — old XLA aborts with
-# "Check failed: sharding.IsManualSubgroup()" when a sharding
-# constraint appears inside a manual subgroup.
-_shim = getattr(jax, "shard_map", None)
-LEGACY_SHARD_MAP = (
-    _shim is None
-    or getattr(_shim, "__module__", "") == "repro.utils.jax_compat")
 
 
 def test_gson_distributed_equivalence(devices8):
@@ -200,18 +188,6 @@ def test_smoke_cells_lower_on_pod_mesh(devices8):
     assert "OK" in out
 
 
-@pytest.mark.skipif(
-    LEGACY_SHARD_MAP,
-    reason="partial-manual shard_map (axis_names={'pod'}) aborts the "
-           "pinned jax 0.4.x XLA (hlo_sharding_util.cc 'Check failed: "
-           "sharding.IsManualSubgroup()'). Not fixable from our side: "
-           "explicit activation constraints inside the region are "
-           "already dropped on the legacy shim (act_sharding.constrain "
-           "+ jax_compat.has_native_shard_map), and the abort persists "
-           "because the legacy partial-AUTO lowering leaves "
-           "GSPMD-propagated inner shardings unmarked as manual "
-           "subgroups. Needs native jax.shard_map — full analysis in "
-           "docs/architecture.md §Distributed")
 def test_train_step_with_compression_and_straggler_masking(devices8):
     out = devices8("""
         import jax, jax.numpy as jnp, numpy as np

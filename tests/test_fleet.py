@@ -50,11 +50,26 @@ def short_spec(variant="multi", **kw) -> gson.RunSpec:
     return gson.RunSpec(**base)
 
 
+FLOAT_FIELDS = ("w", "age", "error", "firing", "threshold")
+
+
 def assert_states_equal(a, b, ctx=""):
     for name in STATE_FIELDS:
         np.testing.assert_array_equal(
             np.asarray(getattr(a, name)), np.asarray(getattr(b, name)),
             err_msg=f"{ctx}: field {name!r} differs")
+
+
+def assert_states_close(a, b, ctx=""):
+    """Discrete fields bitwise, float fields within the 1e-6 of
+    ``tests/test_fleet_mesh.py``."""
+    for name in STATE_FIELDS:
+        x, y = np.asarray(getattr(a, name)), np.asarray(getattr(b, name))
+        if name in FLOAT_FIELDS:
+            assert np.allclose(x, y, atol=1e-6), (ctx, name)
+        else:
+            np.testing.assert_array_equal(
+                x, y, err_msg=f"{ctx}: field {name!r} differs")
 
 
 # ---------------------------------------------------------------------------
@@ -80,8 +95,12 @@ def test_fleet_bit_identical_to_sessions(variant):
 
 
 def test_heterogeneous_samplers_one_cohort_bit_identical():
-    # one sampler per network, same pool shape -> ONE cohort; each
-    # network still matches its own single-surface session bitwise
+    """One sampler per network, same pool shape -> ONE cohort; each
+    network matches its own single-surface session: discrete fields
+    bitwise, floats within 1e-6. The grouped sampler draws every
+    surface in one program, which XLA fuses differently from a
+    single-surface draw; on jax 0.9 that reassociation moves a few
+    weights by one ulp (3e-8) while ids and topology stay equal."""
     spec = short_spec("multi-fused", max_iterations=20)
     fleet = gson.FleetSession(gson.FleetSpec.broadcast(
         spec, seeds=range(len(SURFACES)), samplers=SURFACES))
@@ -92,7 +111,7 @@ def test_heterogeneous_samplers_one_cohort_bit_identical():
         sess.run()
         st_s, _ = sess.result()
         st_f, _ = fleet.result(i)
-        assert_states_equal(st_s, st_f, f"surface {surf}")
+        assert_states_close(st_s, st_f, f"surface {surf}")
 
 
 # ---------------------------------------------------------------------------

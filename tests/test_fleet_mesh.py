@@ -20,9 +20,8 @@ first-class citizen, not an orphaned shard_map program:
     the mesh and still matches dedicated sessions;
   * host-side ``MeshSpec`` validation (no devices needed).
 
-None of these tests skip: the shim path (legacy
-``jax.experimental.shard_map`` behind ``utils.jax_compat``) must pass
-them on every run, which is what the CI ``multi-device`` job enforces.
+None of these tests skip, which is what the CI ``multi-device`` job
+enforces.
 """
 from __future__ import annotations
 
