@@ -239,9 +239,16 @@ def main(argv=None):
     print(f"Euler characteristic {chi} (target {expect_chi}, genus "
           f"{GENUS.get(args.surface, 0)})  signals={stats.signals} "
           f"discarded={stats.discarded}")
-    print(f"phase times: sample {stats.time_sample:.1f}s  "
-          f"step {stats.time_step:.1f}s  "
-          f"convergence-check {stats.time_convergence:.1f}s")
+    if stats.time_sample or stats.time_convergence:
+        print(f"phase times: sample {stats.time_sample:.1f}s  "
+              f"step {stats.time_step:.1f}s  "
+              f"convergence-check {stats.time_convergence:.1f}s")
+    else:
+        # sampling and the check run inside the device program: the
+        # host times the whole step, the trace splits it by phase
+        print(f"step time {stats.time_step:.1f}s (sampling, update and "
+              f"convergence check on the device; their gson.* scopes "
+              f"are in a jax.profiler trace, see README)")
     if args.out:
         nv, nf = export_obj(state, args.out)
         print(f"wrote {args.out}: {nv} vertices, {nf} faces")

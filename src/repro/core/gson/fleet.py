@@ -258,7 +258,10 @@ def fleet_iterate_impl(
     ``(max_parallel, dim)`` signal buffer, run the masked multi-signal
     step with the device m-schedule, and (SOAM) refresh the topology
     ladder on the per-network cadence. Networks outside ``mask`` are
-    frozen (state, key and counter unchanged).
+    frozen (state, key and counter unchanged). The phases carry the
+    named scopes ``gson.sample`` and ``gson.refresh`` (the step's own
+    are in ``multi_signal_step_impl``); :func:`fleet_check_impl` runs
+    under ``gson.check``.
 
     ``fw_aux``: optional batched search structure for stateful Find
     Winners backends (every leaf (B, ...)), carried by
@@ -266,9 +269,10 @@ def fleet_iterate_impl(
     rebuilds per call — correct everywhere (this is what the
     host-dispatched drivers do), just unamortized.
     """
-    keys = jax.vmap(jax.random.split)(fstate.rng)              # (B, 2)
-    rng, k_sig = keys[:, 0], keys[:, 1]
-    signals = sampler(k_sig, cfg.max_parallel)                 # (B, m, dim)
+    with jax.named_scope("gson.sample"):
+        keys = jax.vmap(jax.random.split)(fstate.rng)          # (B, 2)
+        rng, k_sig = keys[:, 0], keys[:, 1]
+        signals = sampler(k_sig, cfg.max_parallel)             # (B, m, dim)
     stateful = getattr(find_winners, "stateful", False)
     if stateful and fw_aux is None:
         fw_aux = jax.vmap(find_winners.build)(fstate.nets.w,
@@ -291,13 +295,15 @@ def fleet_iterate_impl(
         # per-network cadence on the pre-increment global counter, like
         # the superstep; the any() gate skips the (vmapped) refresh
         # entirely on iterations where no live network is due
-        due = mask & (fstate.iteration % cfg.refresh_every == 0)
+        with jax.named_scope("gson.refresh"):
+            due = mask & (fstate.iteration % cfg.refresh_every == 0)
 
-        def do_refresh(n):
-            ref = jax.vmap(lambda s: refresh_topology(s, params))(n)
-            return jax.tree.map(lambda a, b: _where(due, a, b), ref, n)
+            def do_refresh(n):
+                ref = jax.vmap(lambda s: refresh_topology(s, params))(n)
+                return jax.tree.map(lambda a, b: _where(due, a, b), ref, n)
 
-        nets = jax.lax.cond(jnp.any(due), do_refresh, lambda n: n, nets)
+            nets = jax.lax.cond(jnp.any(due), do_refresh, lambda n: n,
+                                nets)
 
     new = fstate.replace(nets=nets, rng=rng,
                          iteration=fstate.iteration + 1)
@@ -328,10 +334,11 @@ def fleet_check_impl(
         done, qe = metrics.qe_convergence(net, pr, cfg.qe_threshold)
         return net, done, qe
 
-    nets, done, qe = jax.vmap(one)(fstate.nets, probes)
-    new = fstate.replace(nets=nets, converged=done,
-                         qe=qe.astype(jnp.float32))
-    return select_fleet(mask, new, fstate)
+    with jax.named_scope("gson.check"):
+        nets, done, qe = jax.vmap(one)(fstate.nets, probes)
+        new = fstate.replace(nets=nets, converged=done,
+                             qe=qe.astype(jnp.float32))
+        return select_fleet(mask, new, fstate)
 
 
 def run_fleet_superstep_impl(

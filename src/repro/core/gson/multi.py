@@ -353,122 +353,128 @@ def multi_signal_step_impl(
     rng, k_lock = jax.random.split(state.rng)
 
     # ---- 1. Find Winners -------------------------------------------------
-    if fw_aux is not None:
-        wid, sid, d2b, _ = find_winners(signals, state.w, state.active,
-                                        aux=fw_aux)
-    else:
-        wid, sid, d2b, _ = find_winners(signals, state.w, state.active)
+    with jax.named_scope("gson.find_winners"):
+        if fw_aux is not None:
+            wid, sid, d2b, _ = find_winners(signals, state.w, state.active,
+                                            aux=fw_aux)
+        else:
+            wid, sid, d2b, _ = find_winners(signals, state.w, state.active)
 
     # ---- 2-3e. dense Update phase (pluggable backend) --------------------
-    up = update_phase(state, signals, wid, sid, d2b, k_lock, params,
-                      signal_mask)
-    selected, adapt, ins = up.selected, up.adapt, up.ins
-    w, firing, error, age = up.w, up.firing, up.error, up.age
-    n_sel = jnp.sum(selected).astype(jnp.int32)
-    nbr = state.nbr
+    with jax.named_scope("gson.update"):
+        up = update_phase(state, signals, wid, sid, d2b, k_lock, params,
+                          signal_mask)
+    # ---- 3f-3h. structural tail ---------------------------------------------
+    with jax.named_scope("gson.tail"):
+        selected, adapt, ins = up.selected, up.adapt, up.ins
+        w, firing, error, age = up.w, up.firing, up.error, up.age
+        n_sel = jnp.sum(selected).astype(jnp.int32)
+        nbr = state.nbr
 
-    # ---- 3f. GWR/SOAM unit insertion -------------------------------------
-    active = state.active
-    threshold = state.threshold
-    topo_state = state.topo_state
-    inconsistent = state.inconsistent_for
-    n_active = state.n_active
-    dropped_units = state.dropped_units
+        # ---- 3f. GWR/SOAM unit insertion ------------------------------------
+        active = state.active
+        threshold = state.threshold
+        topo_state = state.topo_state
+        inconsistent = state.inconsistent_for
+        n_active = state.n_active
+        dropped_units = state.dropped_units
 
-    free_order = jnp.argsort(active, stable=True)       # inactive first
-    n_free = C - n_active
+        free_order = jnp.argsort(active, stable=True)       # inactive first
+        n_free = C - n_active
 
-    if not is_gng:
-        rank = jnp.cumsum(ins.astype(jnp.int32)) - 1
-        fits = ins & (rank < n_free)
-        dropped_units = dropped_units + jnp.sum(ins & ~fits)
-        new_id = jnp.where(fits, free_order[jnp.clip(rank, 0, C - 1)], C)
-        w_new = 0.5 * (w[jnp.clip(wid, 0, C - 1)] + signals)
-        w = w.at[new_id].set(w_new, mode="drop")
-        active = active.at[new_id].set(True, mode="drop")
-        firing = firing.at[new_id].set(1.0, mode="drop")
-        error = error.at[new_id].set(0.0, mode="drop")
-        threshold = threshold.at[new_id].set(
-            threshold[jnp.clip(wid, 0, C - 1)], mode="drop")
-        topo_state = topo_state.at[new_id].set(0, mode="drop")
-        inconsistent = inconsistent.at[new_id].set(0, mode="drop")
-        n_active = n_active + jnp.sum(fits).astype(jnp.int32)
+        if not is_gng:
+            rank = jnp.cumsum(ins.astype(jnp.int32)) - 1
+            fits = ins & (rank < n_free)
+            dropped_units = dropped_units + jnp.sum(ins & ~fits)
+            new_id = jnp.where(fits, free_order[jnp.clip(rank, 0, C - 1)], C)
+            w_new = 0.5 * (w[jnp.clip(wid, 0, C - 1)] + signals)
+            w = w.at[new_id].set(w_new, mode="drop")
+            active = active.at[new_id].set(True, mode="drop")
+            firing = firing.at[new_id].set(1.0, mode="drop")
+            error = error.at[new_id].set(0.0, mode="drop")
+            threshold = threshold.at[new_id].set(
+                threshold[jnp.clip(wid, 0, C - 1)], mode="drop")
+            topo_state = topo_state.at[new_id].set(0, mode="drop")
+            inconsistent = inconsistent.at[new_id].set(0, mode="drop")
+            n_active = n_active + jnp.sum(fits).astype(jnp.int32)
 
-        # edges: (new, b) and (new, s); drop (b, s)
-        e_a = jnp.concatenate([new_id, new_id])
-        e_b = jnp.concatenate([wid, sid])
-        e_m = jnp.concatenate([fits, fits])
-        nbr, age, d1 = topo.insert_edges(nbr, age, e_a, e_b, e_m)
-        nbr, age = topo.remove_edge_pairs(nbr, age, wid, sid, fits)
-        # refresh/insert (b, s) for adapting signals
-        nbr, age, d2_ = topo.insert_edges(nbr, age, wid, sid, adapt)
-        dropped_edges = state.dropped_edges + d1 + d2_
-    else:
-        nbr, age, d2_ = topo.insert_edges(nbr, age, wid, sid, selected)
-        dropped_edges = state.dropped_edges + d2_
+            # edges: (new, b) and (new, s); drop (b, s)
+            e_a = jnp.concatenate([new_id, new_id])
+            e_b = jnp.concatenate([wid, sid])
+            e_m = jnp.concatenate([fits, fits])
+            nbr, age, d1 = topo.insert_edges(nbr, age, e_a, e_b, e_m)
+            nbr, age = topo.remove_edge_pairs(nbr, age, wid, sid, fits)
+            # refresh/insert (b, s) for adapting signals
+            nbr, age, d2_ = topo.insert_edges(nbr, age, wid, sid, adapt)
+            dropped_edges = state.dropped_edges + d1 + d2_
+        else:
+            nbr, age, d2_ = topo.insert_edges(nbr, age, wid, sid, selected)
+            dropped_edges = state.dropped_edges + d2_
 
-    # ---- 3g. GNG periodic insertion at max-error units -------------------
-    eff_old = state.signal_count - state.discarded
-    eff_new = eff_old + n_sel
-    if is_gng:
-        k_cap = 8  # static cap on inserts per iteration
-        n_ins = (eff_new // params.gng_lambda) - (eff_old // params.gng_lambda)
-        n_ins = jnp.clip(n_ins, 0, k_cap)
-        err_masked = jnp.where(active, error, -jnp.inf)
-        _, q_ids = jax.lax.top_k(err_masked, k_cap)
-        q_ids = q_ids.astype(jnp.int32)
-        take = jnp.arange(k_cap) < n_ins
-        # worst neighbor f of each q
-        q_nb = nbr[q_ids]                                  # (k, K)
-        q_nb_err = jnp.where(q_nb >= 0,
-                             error[jnp.clip(q_nb, 0, C - 1)], -jnp.inf)
-        f_slot = jnp.argmax(q_nb_err, axis=1)
-        f_ids = q_nb[jnp.arange(k_cap), f_slot]
-        take = take & (f_ids >= 0)
-        rank = jnp.cumsum(take.astype(jnp.int32)) - 1
-        fits = take & (rank < n_free)
-        dropped_units = dropped_units + jnp.sum(take & ~fits)
-        new_id = jnp.where(fits, free_order[jnp.clip(rank, 0, C - 1)], C)
-        f_safe = jnp.clip(f_ids, 0, C - 1)
-        w_new = 0.5 * (w[q_ids] + w[f_safe])
-        w = w.at[new_id].set(w_new, mode="drop")
-        active = active.at[new_id].set(True, mode="drop")
-        firing = firing.at[new_id].set(1.0, mode="drop")
-        n_active = n_active + jnp.sum(fits).astype(jnp.int32)
-        # error redistribution
-        error = error.at[jnp.where(fits, q_ids, C)].multiply(
-            params.gng_alpha, mode="drop")
-        error = error.at[jnp.where(fits, f_ids, C)].multiply(
-            params.gng_alpha, mode="drop")
-        error = error.at[new_id].set(
-            params.gng_alpha * error[q_ids], mode="drop")
-        e_a = jnp.concatenate([new_id, new_id])
-        e_b = jnp.concatenate([q_ids, f_ids])
-        e_m = jnp.concatenate([fits, fits])
-        nbr, age, d3 = topo.insert_edges(nbr, age, e_a, e_b, e_m)
-        nbr, age = topo.remove_edge_pairs(nbr, age, q_ids, f_ids, fits)
-        dropped_edges = dropped_edges + d3
-        # global error decay, once per effective signal
-        error = error * (1.0 - params.gng_beta) ** n_sel
+        # ---- 3g. GNG periodic insertion at max-error units ------------------
+        eff_old = state.signal_count - state.discarded
+        eff_new = eff_old + n_sel
+        if is_gng:
+            k_cap = 8  # static cap on inserts per iteration
+            n_ins = ((eff_new // params.gng_lambda)
+                     - (eff_old // params.gng_lambda))
+            n_ins = jnp.clip(n_ins, 0, k_cap)
+            err_masked = jnp.where(active, error, -jnp.inf)
+            _, q_ids = jax.lax.top_k(err_masked, k_cap)
+            q_ids = q_ids.astype(jnp.int32)
+            take = jnp.arange(k_cap) < n_ins
+            # worst neighbor f of each q
+            q_nb = nbr[q_ids]                                  # (k, K)
+            q_nb_err = jnp.where(q_nb >= 0,
+                                 error[jnp.clip(q_nb, 0, C - 1)], -jnp.inf)
+            f_slot = jnp.argmax(q_nb_err, axis=1)
+            f_ids = q_nb[jnp.arange(k_cap), f_slot]
+            take = take & (f_ids >= 0)
+            rank = jnp.cumsum(take.astype(jnp.int32)) - 1
+            fits = take & (rank < n_free)
+            dropped_units = dropped_units + jnp.sum(take & ~fits)
+            new_id = jnp.where(fits, free_order[jnp.clip(rank, 0, C - 1)], C)
+            f_safe = jnp.clip(f_ids, 0, C - 1)
+            w_new = 0.5 * (w[q_ids] + w[f_safe])
+            w = w.at[new_id].set(w_new, mode="drop")
+            active = active.at[new_id].set(True, mode="drop")
+            firing = firing.at[new_id].set(1.0, mode="drop")
+            n_active = n_active + jnp.sum(fits).astype(jnp.int32)
+            # error redistribution
+            error = error.at[jnp.where(fits, q_ids, C)].multiply(
+                params.gng_alpha, mode="drop")
+            error = error.at[jnp.where(fits, f_ids, C)].multiply(
+                params.gng_alpha, mode="drop")
+            error = error.at[new_id].set(
+                params.gng_alpha * error[q_ids], mode="drop")
+            e_a = jnp.concatenate([new_id, new_id])
+            e_b = jnp.concatenate([q_ids, f_ids])
+            e_m = jnp.concatenate([fits, fits])
+            nbr, age, d3 = topo.insert_edges(nbr, age, e_a, e_b, e_m)
+            nbr, age = topo.remove_edge_pairs(nbr, age, q_ids, f_ids, fits)
+            dropped_edges = dropped_edges + d3
+            # global error decay, once per effective signal
+            error = error * (1.0 - params.gng_beta) ** n_sel
 
-    # ---- 3h. expiry + pruning --------------------------------------------
-    nbr, age, _ = topo.expire_edges(nbr, age, params.age_max)
-    active, _ = topo.prune_isolated(active, nbr, firing)
-    n_active = jnp.sum(active).astype(jnp.int32)
-    nbr = jnp.where(active[:, None], nbr, jnp.int32(-1))
-    nbr, age = topo.drop_edges_to_inactive(nbr, age, active)
+        # ---- 3h. expiry + pruning -------------------------------------------
+        nbr, age, _ = topo.expire_edges(nbr, age, params.age_max)
+        active, _ = topo.prune_isolated(active, nbr, firing)
+        n_active = jnp.sum(active).astype(jnp.int32)
+        nbr = jnp.where(active[:, None], nbr, jnp.int32(-1))
+        nbr, age = topo.drop_edges_to_inactive(nbr, age, active)
 
-    out = state.replace(
-        w=w, active=active, nbr=nbr, age=age, error=error, firing=firing,
-        threshold=threshold, topo_state=topo_state,
-        inconsistent_for=inconsistent, n_active=n_active,
-        signal_count=state.signal_count + m_eff,
-        discarded=state.discarded + (m_eff - n_sel),
-        dropped_edges=dropped_edges, dropped_units=dropped_units, rng=rng,
-    )
+        out = state.replace(
+            w=w, active=active, nbr=nbr, age=age, error=error, firing=firing,
+            threshold=threshold, topo_state=topo_state,
+            inconsistent_for=inconsistent, n_active=n_active,
+            signal_count=state.signal_count + m_eff,
+            discarded=state.discarded + (m_eff - n_sel),
+            dropped_edges=dropped_edges, dropped_units=dropped_units, rng=rng,
+        )
     # ---- 3i. SOAM: topology states + adaptive insertion threshold --------
     if is_soam and refresh_states:
-        out = refresh_topology(out, params)
+        with jax.named_scope("gson.refresh"):
+            out = refresh_topology(out, params)
     return out
 
 

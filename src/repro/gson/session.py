@@ -34,6 +34,7 @@ on ``FleetSpec`` (see ``repro.gson.fleet``).
 """
 from __future__ import annotations
 
+import itertools
 import time
 from dataclasses import dataclass, field
 from typing import Callable, Iterator
@@ -47,6 +48,10 @@ from repro.core.gson import fleet as fleet_core
 from repro.core.gson import metrics
 from repro.gson import registry
 from repro.gson.spec import RunSpec, resolve
+from repro.utils.timing import span
+
+# the ``session`` id every trace span of one Session carries
+_SESSION_IDS = itertools.count()
 
 
 @dataclass
@@ -97,6 +102,7 @@ class Session:
                  checkpoint_every: int = 0, keep: int = 3):
         self.spec = spec
         self.strategy, self.rt = resolve(spec)
+        self.id = self.rt.session_id = next(_SESSION_IDS)
         self._rng0 = rng if rng is not None else jax.random.key(seed)
         self._callbacks: list[HistoryCallback] = []
         if on_history is not None:
@@ -128,10 +134,11 @@ class Session:
             return False
         if self.iteration >= self.spec.max_iterations:
             return False
-        if (self.started
-                and int(self.state.signal_count) >= self.spec.max_signals):
-            return False
-        return True
+        if not self.started:
+            return True
+        with span("readback", session=self.id):
+            signals = int(self.state.signal_count)
+        return signals < self.spec.max_signals
 
     # ------------------------------------------------------------------
     def _init_from(self, rng0: jax.Array):
@@ -155,17 +162,28 @@ class Session:
         # probe init, and BENCH_gson.json per-iteration rows divide
         # time_total by iterations — counting setup here would skew the
         # perf trajectory against the PR1 baseline
-        self.state, self.rt.probes, self._rng = self._init_from(
-            self._rng0)
-        self.strategy.prepare(self.rt)
+        with span("session.start", session=self.id):
+            self.state, self.rt.probes, self._rng = self._init_from(
+                self._rng0)
+            self.strategy.prepare(self.rt)
 
-    def _emit(self, row: dict) -> None:
-        self.stats.history.append(row)
-        for f in self._callbacks:
-            f(row)
-        if self.verbose:
-            print(f"  it={row['iteration']:6d} units={row['units']:6d} "
-                  f"signals={row['signals']:9d} qe={row['qe']:.5f}")
+    def _emit(self, qe: float) -> dict:
+        """Build the history row of a completed check and publish it."""
+        with span("session.emit", session=self.id):
+            with span("readback", session=self.id):
+                units = int(self.state.n_active)
+            with span("readback", session=self.id):
+                signals = int(self.state.signal_count)
+            row = {"iteration": self.iteration, "units": units,
+                   "signals": signals, "qe": qe}
+            self.stats.history.append(row)
+            for f in self._callbacks:
+                f(row)
+            if self.verbose:
+                print(f"  it={row['iteration']:6d} "
+                      f"units={row['units']:6d} "
+                      f"signals={row['signals']:9d} qe={row['qe']:.5f}")
+        return row
 
     # ------------------------------------------------------------------
     def stream(self, budget: int | None = None) -> Iterator[dict]:
@@ -180,47 +198,48 @@ class Session:
         t_wall = time.perf_counter()
         try:
             while self.active and (budget is None or spent < budget):
-                max_iters = spec.max_iterations - self.iteration
-                if budget is not None:
-                    max_iters = min(max_iters, budget - spent)
-                try:
-                    res = self.strategy.step(self.rt, self.state,
-                                             self._rng, self.iteration,
-                                             max_iters)
-                except Exception as e:            # noqa: BLE001
-                    # first-call lowering failure of a kernel backend:
-                    # swap in the reference pair (identical results,
-                    # slower) and retry; anything else re-raises
-                    fb = (None if self._stepped
-                          else registry.reference_fallback(
-                              self.rt.find_winners,
-                              self.rt.update_phase, e))
-                    if fb is None:
-                        raise
-                    self.rt.find_winners, self.rt.update_phase = fb
-                    res = self.strategy.step(self.rt, self.state,
-                                             self._rng, self.iteration,
-                                             max_iters)
-                self._stepped = True
-                self.state, self._rng = res.state, res.rng
-                self.iteration += res.iterations
-                spent += res.iterations
-                self.stats.time_sample += res.timings.get("sample", 0.0)
-                self.stats.time_step += res.timings.get("step", 0.0)
-                self.stats.time_convergence += res.timings.get(
-                    "convergence", 0.0)
-                if res.done:
-                    self.converged = True
-                    self.stats.converged = True
-                    self.stats.quantization_error = res.qe
-                if res.checked:
-                    row = {
-                        "iteration": self.iteration,
-                        "units": int(self.state.n_active),
-                        "signals": int(self.state.signal_count),
-                        "qe": res.qe,
-                    }
-                    self._emit(row)
+                row = None
+                with span("superstep", session=self.id,
+                          iteration=self.iteration):
+                    max_iters = spec.max_iterations - self.iteration
+                    if budget is not None:
+                        max_iters = min(max_iters, budget - spent)
+                    try:
+                        res = self.strategy.step(self.rt, self.state,
+                                                 self._rng, self.iteration,
+                                                 max_iters)
+                    except Exception as e:            # noqa: BLE001
+                        # first-call lowering failure of a kernel
+                        # backend: swap in the reference pair (identical
+                        # results, slower) and retry; anything else
+                        # re-raises
+                        fb = (None if self._stepped
+                              else registry.reference_fallback(
+                                  self.rt.find_winners,
+                                  self.rt.update_phase, e))
+                        if fb is None:
+                            raise
+                        self.rt.find_winners, self.rt.update_phase = fb
+                        res = self.strategy.step(self.rt, self.state,
+                                                 self._rng, self.iteration,
+                                                 max_iters)
+                    self._stepped = True
+                    self.state, self._rng = res.state, res.rng
+                    self.iteration += res.iterations
+                    spent += res.iterations
+                    self.stats.time_sample += res.timings.get("sample", 0.0)
+                    self.stats.time_step += res.timings.get("step", 0.0)
+                    self.stats.time_convergence += res.timings.get(
+                        "convergence", 0.0)
+                    if res.done:
+                        self.converged = True
+                        self.stats.converged = True
+                        self.stats.quantization_error = res.qe
+                    if res.checked:
+                        row = self._emit(res.qe)
+                # the span closes before the yield: the caller's work
+                # between rows is not the session's
+                if row is not None:
                     yield row
                 if (self._mgr is not None and self.checkpoint_every > 0
                         and self.iteration - self._last_ckpt
@@ -243,15 +262,16 @@ class Session:
     def result(self):
         """Finalize and return ``(state, stats)`` (engine-compatible)."""
         self._start()
-        st = self.state
-        self.stats.iterations = self.iteration
-        self.stats.signals = int(st.signal_count)
-        self.stats.discarded = int(st.discarded)
-        self.stats.units = int(st.n_active)
-        self.stats.connections = metrics.edge_count(st)
-        if np.isnan(self.stats.quantization_error):
-            self.stats.quantization_error = float(
-                metrics.quantization_error(st, self.rt.probes))
+        with span("session.result", session=self.id):
+            st = self.state
+            self.stats.iterations = self.iteration
+            self.stats.signals = int(st.signal_count)
+            self.stats.discarded = int(st.discarded)
+            self.stats.units = int(st.n_active)
+            self.stats.connections = metrics.edge_count(st)
+            if np.isnan(self.stats.quantization_error):
+                self.stats.quantization_error = float(
+                    metrics.quantization_error(st, self.rt.probes))
         return st, self.stats
 
     # ------------------------------------------------------------------

@@ -10,7 +10,9 @@ object with three hooks:
   prepare(rt)                  — resolve derived config once per run
                                  (e.g. the fused superstep's buffer size)
   step(rt, state, rng, it, n)  — advance up to ``n`` iterations, timing
-                                 the paper's phases; returns a StepResult
+                                 the paper's phases through
+                                 ``repro.utils.timing.span``; returns a
+                                 StepResult
   convergence(rt, state)       — the termination predicate (shared
                                  default: SOAM topology criterion or
                                  quantization error)
@@ -36,7 +38,6 @@ variants ("single", "indexed") remain host loops by design.
 from __future__ import annotations
 
 import dataclasses
-import time
 from dataclasses import dataclass, field
 from typing import Any, Protocol, runtime_checkable
 
@@ -51,6 +52,7 @@ from repro.core.gson.single import single_signal_scan
 from repro.core.gson.state import GSONParams
 from repro.core.gson.superstep import SuperstepConfig, next_pow2
 from repro.gson.registry import MODELS, VARIANTS
+from repro.utils.timing import span
 
 DEFAULT_BBOX = ((-3.0, -3.0, -3.0), (3.0, 3.0, 3.0))
 
@@ -112,6 +114,7 @@ class Runtime:
     update_phase: Any = None      # UpdatePhaseFn | None
     probes: jax.Array | None = None
     scratch: dict = field(default_factory=dict)   # strategy-owned
+    session_id: int = 0           # the ``session`` id of its trace spans
 
     @property
     def check_every(self) -> int:
@@ -187,24 +190,21 @@ class _HostVariant:
     def step(self, rt: Runtime, state, rng, it: int,
              max_iters: int) -> StepResult:
         timings = {}
-        t0 = time.perf_counter()
-        rng, k_sig = jax.random.split(rng)
-        signals = rt.sampler(k_sig, self._m(rt, state))
-        signals.block_until_ready()
-        timings["sample"] = time.perf_counter() - t0
+        with span("sample", timings, session=rt.session_id):
+            rng, k_sig = jax.random.split(rng)
+            signals = rt.sampler(k_sig, self._m(rt, state))
+            signals.block_until_ready()
 
-        t0 = time.perf_counter()
-        state = self._update(rt, state, signals, it)
-        state.w.block_until_ready()
-        timings["step"] = time.perf_counter() - t0
+        with span("step", timings, session=rt.session_id):
+            state = self._update(rt, state, signals, it)
+            state.w.block_until_ready()
 
         it += 1
         checked = it % rt.check_every == 0
         done, qe = False, float("nan")
         if checked:
-            t0 = time.perf_counter()
-            done, qe, state = self.convergence(rt, state)
-            timings["convergence"] = time.perf_counter() - t0
+            with span("convergence", timings, session=rt.session_id):
+                done, qe, state = self.convergence(rt, state)
         return StepResult(state, rng, 1, checked, done, qe, timings)
 
 
@@ -271,25 +271,28 @@ class MultiVariant(_FleetBacked):
              max_iters: int) -> StepResult:
         cfg = rt.scratch["fleet_cfg"]
         one = jnp.ones((1,), bool)
-        t0 = time.perf_counter()
-        fs = fleet_core.wrap_single(state, rng, it)
-        fs = fleet_core.fleet_iterate(
-            fs, one, sampler=rt.scratch["fleet_sampler"],
-            params=rt.params, cfg=cfg, find_winners=rt.find_winners,
-            update_phase=rt.update_phase)
-        it += 1
-        checked = it % rt.check_every == 0
-        done, qe = False, float("nan")
-        if checked:
-            fs = fleet_core.fleet_check(fs, rt.probes[None], one,
-                                        params=rt.params, cfg=cfg)
-            done, qe = bool(fs.converged[0]), float(fs.qe[0])
-        state, rng = fs.network(0), fs.rng[0]
-        state.w.block_until_ready()
+        timings = {}
         # sampling runs inside the device program now; the whole
         # iteration is accounted under "step" like the fused variant
-        return StepResult(state, rng, 1, checked, done, qe,
-                          {"step": time.perf_counter() - t0})
+        with span("step", timings, session=rt.session_id):
+            fs = fleet_core.wrap_single(state, rng, it)
+            fs = fleet_core.fleet_iterate(
+                fs, one, sampler=rt.scratch["fleet_sampler"],
+                params=rt.params, cfg=cfg, find_winners=rt.find_winners,
+                update_phase=rt.update_phase)
+            it += 1
+            checked = it % rt.check_every == 0
+            done, qe = False, float("nan")
+            if checked:
+                fs = fleet_core.fleet_check(fs, rt.probes[None], one,
+                                            params=rt.params, cfg=cfg)
+                with span("readback", session=rt.session_id):
+                    done = bool(fs.converged[0])
+                with span("readback", session=rt.session_id):
+                    qe = float(fs.qe[0])
+            state, rng = fs.network(0), fs.rng[0]
+            state.w.block_until_ready()
+        return StepResult(state, rng, 1, checked, done, qe, timings)
 
 
 class SingleVariant(_HostVariant):
@@ -355,29 +358,42 @@ class FusedVariant(_FleetBacked):
     def step(self, rt: Runtime, state, rng, it: int,
              max_iters: int) -> StepResult:
         ss = rt.scratch["fleet_cfg"]
-        # bound by BOTH remaining budgets: iterations, and signals (worst
-        # case one iteration consumes max_parallel signals) — overshoot
-        # is at most one iteration's m, like the host loop. The bound is
-        # a dynamic operand, so partial-length supersteps share one jit
-        # signature instead of retracing per length.
-        sig_left = rt.spec.max_signals - int(state.signal_count)
-        length = max(1, min(ss.length, max_iters,
-                            -(-sig_left // ss.max_parallel)))
-        t0 = time.perf_counter()
-        fs = fleet_core.wrap_single(state, rng, it)
-        fs, steps = fleet_core.run_fleet_superstep(
-            fs, rt.probes[None], jnp.asarray([length], jnp.int32),
-            sampler=rt.scratch["fleet_sampler"], params=rt.params,
-            cfg=ss, find_winners=rt.find_winners,
-            update_phase=rt.update_phase)
-        state, rng = fs.network(0), fs.rng[0]
-        state.w.block_until_ready()
-        dt = time.perf_counter() - t0
-        # the fused variant cannot split phases (that is the point):
-        # its whole superstep time is accounted under "step"
-        return StepResult(state, rng, int(steps[0]), True,
-                          bool(fs.converged[0]), float(fs.qe[0]),
-                          {"step": dt})
+        ids = {"session": rt.session_id}
+        timings = {}
+        # the fused variant cannot split phases on the host (that is the
+        # point): its whole superstep is accounted under "step", and the
+        # phases are the device program's named scopes in the trace
+        with span("step", timings, **ids):
+            with span("step.prepare", **ids):
+                # bound by BOTH remaining budgets: iterations, and
+                # signals (worst case one iteration consumes
+                # max_parallel signals) — overshoot is at most one
+                # iteration's m, like the host loop. The bound is a
+                # dynamic operand, so partial-length supersteps share
+                # one jit signature instead of retracing per length.
+                with span("readback", **ids):
+                    signals = int(state.signal_count)
+                sig_left = rt.spec.max_signals - signals
+                length = max(1, min(ss.length, max_iters,
+                                    -(-sig_left // ss.max_parallel)))
+                fs = fleet_core.wrap_single(state, rng, it)
+            with span("step.dispatch", **ids):
+                fs, steps = fleet_core.run_fleet_superstep(
+                    fs, rt.probes[None], jnp.asarray([length], jnp.int32),
+                    sampler=rt.scratch["fleet_sampler"], params=rt.params,
+                    cfg=ss, find_winners=rt.find_winners,
+                    update_phase=rt.update_phase)
+            with span("step.unwrap", **ids):
+                state, rng = fs.network(0), fs.rng[0]
+            with span("step.wait", **ids):
+                state.w.block_until_ready()
+        with span("readback", **ids):
+            n = int(steps[0])
+        with span("readback", **ids):
+            done = bool(fs.converged[0])
+        with span("readback", **ids):
+            qe = float(fs.qe[0])
+        return StepResult(state, rng, n, True, done, qe, timings)
 
 
 # stateless singletons: one instance per registered name

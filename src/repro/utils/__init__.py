@@ -1,2 +1,2 @@
-from repro.utils.timing import Timer, timed
+from repro.utils.timing import span, timed
 from repro.utils.trees import tree_bytes, tree_param_count

@@ -1,40 +1,69 @@
-"""Lightweight wall-clock timing helpers (CPU benchmarking only)."""
+"""Host timing: the program's one tracing entry point, and a
+block-until-ready micro-timer.
+
+:func:`span` marks a stretch of host work as ``gson.<name>`` in the
+profiler's trace (a ``jax.profiler.TraceAnnotation``, on the same clock
+as the device operations) and, given a ``timings`` dict, adds its
+wall-clock seconds there too, so stats and trace share one boundary.
+"""
 from __future__ import annotations
 
-import contextlib
 import time
-from dataclasses import dataclass, field
+
+from jax.profiler import TraceAnnotation
+
+PREFIX = "gson."
+_recording = TraceAnnotation.is_enabled
 
 
-@dataclass
-class Timer:
-    """Accumulating named timer: ``with timer("phase"): ...``."""
+class _Off:
+    """What :func:`span` returns with the profiler off and no timings."""
 
-    totals: dict = field(default_factory=dict)
-    counts: dict = field(default_factory=dict)
+    __slots__ = ()
 
-    @contextlib.contextmanager
-    def __call__(self, name: str):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            dt = time.perf_counter() - t0
-            self.totals[name] = self.totals.get(name, 0.0) + dt
-            self.counts[name] = self.counts.get(name, 0) + 1
+    def __enter__(self):
+        return self
 
-    def mean(self, name: str) -> float:
-        return self.totals[name] / max(self.counts.get(name, 1), 1)
+    def __exit__(self, *exc):
+        return False
 
-    def summary(self) -> str:
-        total = sum(self.totals.values()) or 1.0
-        lines = []
-        for k in sorted(self.totals, key=self.totals.get, reverse=True):
-            lines.append(
-                f"{k:>16s}: {self.totals[k]:10.4f}s "
-                f"({100.0 * self.totals[k] / total:5.1f}%)  n={self.counts[k]}"
-            )
-        return "\n".join(lines)
+
+_OFF = _Off()
+
+
+class _Span:
+    __slots__ = ("_name", "_timings", "_me", "_t0")
+
+    def __init__(self, name: str, timings: dict | None, ids: dict):
+        self._name, self._timings = name, timings
+        # the profiler formats ``ids`` only while it records
+        self._me = (TraceAnnotation(PREFIX + name, **ids) if _recording()
+                    else None)
+        self._t0 = time.perf_counter()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        if self._timings is not None:
+            t = self._timings
+            t[self._name] = (t.get(self._name, 0.0)
+                             + time.perf_counter() - self._t0)
+        if self._me is not None:
+            self._me.__exit__(*exc)
+        return False
+
+
+def span(name: str, timings: dict | None = None, **ids):
+    """``with span(name, timings=None, **ids): ...``
+
+    Opens the profiler annotation ``gson.<name>`` carrying ``ids`` as
+    event stats, and adds the elapsed ``perf_counter`` seconds to
+    ``timings[name]`` when a dict is given. With the profiler off and
+    no dict it does nothing."""
+    if timings is None and not _recording():
+        return _OFF
+    return _Span(name, timings, ids)
 
 
 def timed(fn, *args, n: int = 5, warmup: int = 1, **kwargs):
