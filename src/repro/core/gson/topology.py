@@ -47,12 +47,15 @@ def _rank_within_rows(rows: jax.Array) -> jax.Array:
     Invalid rows must already be set to a large sentinel so they group
     together (their ranks are unused).
     """
-    order = jnp.argsort(rows, stable=True)
-    sorted_rows = rows[order]
-    # rank in sorted order = position - first position of this row value
-    first = jnp.searchsorted(sorted_rows, sorted_rows, side="left")
-    rank_sorted = jnp.arange(rows.shape[0], dtype=jnp.int32) - first.astype(jnp.int32)
-    rank = jnp.zeros_like(rank_sorted).at[order].set(rank_sorted)
+    iota = jnp.arange(rows.shape[0], dtype=jnp.int32)
+    sorted_rows, order = jax.lax.sort((rows, iota), num_keys=1,
+                                      is_stable=True)
+    # rank in sorted order = position - first position of this row value;
+    # the first position is the latest run start at or before it (a
+    # cumulative max, where a binary search would gather once per round)
+    start = (iota == 0) | (sorted_rows != jnp.roll(sorted_rows, 1))
+    first = jax.lax.cummax(jnp.where(start, iota, 0))
+    rank = jnp.zeros_like(iota).at[order].set(iota - first)
     return rank
 
 
@@ -95,11 +98,7 @@ def insert_edges(nbr: jax.Array, age: jax.Array, a: jax.Array, b: jax.Array,
     lo = jnp.minimum(a, b)
     hi = jnp.maximum(a, b)
     key = jnp.where(new, lo * C + hi, jnp.iinfo(jnp.int32).max)
-    order = jnp.argsort(key)
-    skey = key[order]
-    first = jnp.concatenate([jnp.ones((1,), bool), skey[1:] != skey[:-1]])
-    uniq = jnp.zeros((m,), bool).at[order].set(first)
-    new = new & uniq
+    new = new & (_rank_within_rows(key) == 0)
 
     # --- directed entries, rank within target row, pick free slots ---
     rows = jnp.concatenate([a, b])

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.gson import topology
 from repro.core.gson.engine import EngineConfig, GSONEngine
 from repro.core.gson.multi import (find_winners_reference,
                                    multi_signal_step_impl, refresh_topology)
@@ -143,6 +144,50 @@ def test_superstep_equals_sequential_masked_steps(model):
     # history is the scan form's per-iteration n_active trace
     assert res.history.shape == (cfg.length,)
     assert int(res.history[-1]) == int(st_seq.n_active)
+
+
+def _searchsorted_rank(rows):
+    """The collision rank as a binary search found it, kept to check the
+    cumulative-max form against."""
+    order = jnp.argsort(rows, stable=True)
+    sorted_rows = rows[order]
+    first = jnp.searchsorted(sorted_rows, sorted_rows, side="left")
+    rank_sorted = (jnp.arange(rows.shape[0], dtype=jnp.int32)
+                   - first.astype(jnp.int32))
+    return jnp.zeros_like(rank_sorted).at[order].set(rank_sorted)
+
+
+def _soam_c768_steps(steps=6):
+    """SOAM at capacity 768 on a 1024-row buffer with 700 valid rows, from
+    256 habituated seeds, so unit and edge insertions collide in rows."""
+    p = GSONParams(model="soam", insertion_threshold=0.35)
+    sampler = make_sampler("sphere")
+    st = init_state(jax.random.key(0), capacity=768, dim=3, max_deg=16,
+                    seed_points=sampler(jax.random.key(1), 256),
+                    init_threshold=p.insertion_threshold)
+    st = st.replace(firing=jnp.full((768,), 0.05),
+                    threshold=jnp.full((768,), 0.1))
+    mask = jnp.arange(1024) < 700
+
+    @jax.jit   # traced afresh on every call, after any monkeypatch
+    def step(st, key):
+        return multi_signal_step_impl(st, sampler(key, 1024), p,
+                                      refresh_states=False, signal_mask=mask)
+
+    for i in range(steps):
+        st = step(st, jax.random.key(100 + i))
+    return st
+
+
+def test_rank_by_cummax_grows_the_searchsorted_network(monkeypatch):
+    new = _soam_c768_steps()
+    monkeypatch.setattr(topology, "_rank_within_rows", _searchsorted_rank)
+    old = _soam_c768_steps()
+    assert int(new.n_active) > 256 and int(jnp.sum(new.nbr >= 0)) > 1000
+    for field in ("nbr", "age", "active", "w"):
+        np.testing.assert_array_equal(np.asarray(getattr(new, field)),
+                                      np.asarray(getattr(old, field)),
+                                      err_msg=field)
 
 
 def test_scan_and_while_forms_agree():

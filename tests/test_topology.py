@@ -1,8 +1,10 @@
 """Unit tests for the SOAM topological state ladder on hand-built graphs."""
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core.gson import topology as topo
 from repro.core.gson.state import (ACTIVE, CONNECTED, DISK, HABITUATED,
@@ -121,3 +123,44 @@ def test_drop_edges_to_inactive():
     nbr = jnp.where(active[:, None], nbr, jnp.int32(-1))
     nbr2, _ = topo.drop_edges_to_inactive(nbr, age, active)
     assert int(jnp.sum(nbr2 >= 0)) == 0  # both edges referenced unit 1
+
+
+def _numpy_rank_within_rows(rows):
+    """Each entry's count of earlier entries with the same value."""
+    rank = np.empty_like(rows)
+    seen = {}
+    for i, r in enumerate(rows.tolist()):
+        rank[i] = seen.get(r, 0)
+        seen[r] = rank[i] + 1
+    return rank
+
+
+@pytest.mark.parametrize("n", [1, 2, 7, 2048, 4096])
+def test_rank_within_rows_matches_numpy(n):
+    rng = np.random.default_rng(n)
+    rows = rng.integers(0, max(1, n // 8), n).astype(np.int32)
+    rows[rng.random(n) < 0.5] = int(topo._BIG)
+    got = np.asarray(jax.jit(topo._rank_within_rows)(jnp.asarray(rows)))
+    np.testing.assert_array_equal(got, _numpy_rank_within_rows(rows))
+
+
+def _primitive_names(jaxpr):
+    """Names of every primitive in ``jaxpr`` and its sub-jaxprs."""
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _primitive_names(sub)
+
+
+def test_insert_edges_has_no_loop_at_c768_shapes():
+    # the collision rank is a sort and a cumulative max: a binary search
+    # (a loop of dependent gathers; jnp.searchsorted traces to a scan,
+    # which XLA lowers to a while) must not come back
+    C, Kc, m = 768, 16, 2048
+    jaxpr = jax.make_jaxpr(topo.insert_edges)(
+        jnp.full((C, Kc), -1, jnp.int32), jnp.zeros((C, Kc), jnp.float32),
+        jnp.zeros((m,), jnp.int32), jnp.zeros((m,), jnp.int32),
+        jnp.zeros((m,), bool))
+    names = set(_primitive_names(jaxpr.jaxpr))
+    assert not names & {"while", "scan"}, sorted(names)
+    assert "sort" in names and "cummax" in names
