@@ -2,10 +2,12 @@
 
 Everything a cell is made of is found by name from ``BENCHMARK.json``:
 its configuration (``bench/configs/<config>.json``), its traffic
-(``bench/traffic/<traffic>.json``, played by ``bench/generator.py``)
-and one reader per per-layer metric (``bench/metrics/<metric>.py``).
-A new cell, configuration, traffic mix or metric is a new file and a
-manifest entry; nothing here names one.
+(``bench/traffic/<traffic>.json``, played by ``bench/generator.py``),
+the surfaces its jobs sample (``bench/reference/surfaces/<surface>.py``:
+genus and reference sampler) and one reader per per-layer metric
+(``bench/metrics/<metric>.py``). A new cell, configuration, traffic
+mix, surface or metric is a new file and a manifest entry; nothing
+here names one.
 
 From the program the harness takes only the system under test (the
 ``repro.gson`` session API and the backend the configuration names),
@@ -30,7 +32,7 @@ from typing import Any, Callable
 
 import numpy as np
 
-from bench import generator
+from bench import generator, reference
 from bench import trace as tr
 
 BENCH = os.path.dirname(os.path.abspath(__file__))
@@ -111,6 +113,15 @@ def find_cell(workload: str) -> Cell:
     config = load_json(os.path.join(ROOT, cfg["file"]))
     traffic = load_json(traffic_file(w["traffic"]))
     generator.validate(traffic)
+    for s in generator.surfaces(traffic):
+        path = reference.surface_file(s)
+        if not os.path.isfile(path):
+            raise BenchError(f"traffic {w['traffic']!r} names surface "
+                             f"{s!r}, which has no file "
+                             f"{os.path.relpath(path, ROOT)}")
+        if s not in config["insertion_threshold"]:
+            raise BenchError(f"config {w['config']!r} states no "
+                             f"insertion_threshold for surface {s!r}")
     e2e = [m for m in man["end_to_end"] if applies(m, workload)]
     layer = [m for m in man["per_layer"] if applies(m, workload)]
     return Cell(workload, int(w["chips"]), config, traffic, e2e, layer)
@@ -282,9 +293,6 @@ class JobRecord:
         return self.converged and self.chi == self.chi_expected
 
 
-GENUS = {"sphere": 0, "torus": 1, "eight": 2, "trefoil": 1}
-
-
 def kernels_of(sess):
     """The (find_winners, update_phase) pair a ``gson.Session`` runs, or
     None for a session object without one (the reference in the
@@ -352,7 +360,8 @@ def play_window(make_session: Callable, traffic: dict, seed: int,
             rec.converged = bool(sess.converged)
             rec.chi = soam.euler_characteristic(
                 np.asarray(state.nbr), np.asarray(state.active))
-            rec.chi_expected = 2 - 2 * GENUS[job.surface]
+            genus = reference.surface(job.surface).GENUS
+            rec.chi_expected = 2 - 2 * genus
         rec.end = time.perf_counter()
         if kernels_of(sess) != held[job.surface]:
             raise BenchError(f"{job.surface} job: the session's kernels "

@@ -3,12 +3,14 @@
 A straightforward single-file statement of the semantics the system
 under test implements (the multi-signal iteration of arXiv:1503.08294,
 Sec. 2.2, with SOAM's rule set, Piastra 2012, in this repository's
-batched form): surface sampling, the device m-schedule, exact
-Find Winners, the winner lock, the Update, the structural tail (unit
-insertion, edge insertion, expiry, pruning), the periodic topology
-refresh and the convergence check. It imports nothing of the program:
-every rule is written out here, in plain ``jax.numpy`` at float32 with
-``HIGHEST`` contractions, and no kernel, fleet or session code is used.
+batched form): the device m-schedule, exact Find Winners, the winner
+lock, the Update, the structural tail (unit insertion, edge insertion,
+expiry, pruning), the periodic topology refresh and the convergence
+check. Each surface it samples is a file of its own, found by name
+(``bench/reference/surfaces/<name>.py``, see ``bench.reference``). It
+imports nothing of the program: every rule is written out here, in
+plain ``jax.numpy`` at float32 with ``HIGHEST`` contractions, and no
+kernel, fleet or session code is used.
 
 The harness checks the program against it superstep by superstep: from
 the program's network at the start of a superstep (or from the job's
@@ -29,6 +31,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bench import reference
 
 NO_NBR = -1
 BIG32 = int(np.iinfo(np.int32).max)
@@ -87,32 +91,6 @@ class Params:
     refresh_every: int
     check_every: int
     precision: str = "highest"   # "highest" | "high" | "bf16"
-
-
-# ---------------------------------------------------------------------------
-# surfaces
-
-def _sphere(rng, n):
-    v = jax.random.normal(rng, (n, 3))
-    v = v / jnp.linalg.norm(v, axis=1, keepdims=True)
-    return v.astype(jnp.float32)
-
-
-def _torus(rng, n, big_r=1.0, small_r=0.35):
-    k_theta, k_phi, k_rej = jax.random.split(rng, 3)
-    theta = jax.random.uniform(k_theta, (n,), minval=0.0, maxval=2 * jnp.pi)
-    m = 4 * n
-    phi = jax.random.uniform(k_phi, (m,), minval=0.0, maxval=2 * jnp.pi)
-    u = jax.random.uniform(k_rej, (m,))
-    accept = u < (big_r + small_r * jnp.cos(phi)) / (big_r + small_r)
-    phi = phi[jnp.argsort(~accept, stable=True)[:n]]
-    x = (big_r + small_r * jnp.cos(phi)) * jnp.cos(theta)
-    y = (big_r + small_r * jnp.cos(phi)) * jnp.sin(theta)
-    z = small_r * jnp.sin(phi)
-    return jnp.stack([x, y, z], axis=1).astype(jnp.float32)
-
-
-SURFACES = {"sphere": _sphere, "torus": _torus}
 
 
 # ---------------------------------------------------------------------------
@@ -493,20 +471,20 @@ def superstep(carry: Carry, max_steps, sample, p: Params):
 @partial(jax.jit, static_argnames=("surface", "p"))
 def start(seed_key, *, surface: str, p: Params):
     """-> Carry at iteration 0."""
-    net, key = init(seed_key, SURFACES[surface], p)
+    net, key = init(seed_key, reference.surface(surface).sample, p)
     return Carry(net, key, jnp.int32(0), jnp.asarray(False))
 
 
 @partial(jax.jit, static_argnames=("surface", "p"))
 def advance(carry: Carry, max_steps, *, surface: str, p: Params):
     """One superstep from ``carry`` -> (carry, iterations run)."""
-    return superstep(carry, max_steps, SURFACES[surface], p)
+    return superstep(carry, max_steps, reference.surface(surface).sample, p)
 
 
 @partial(jax.jit, static_argnames=("surface", "p"))
 def resume_key(seed_key, iteration, *, surface: str, p: Params):
     """The sampling key of a job at ``iteration``."""
-    _, key = init(seed_key, SURFACES[surface], p)
+    _, key = init(seed_key, reference.surface(surface).sample, p)
     return sampling_key(key, iteration)
 
 
